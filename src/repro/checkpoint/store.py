@@ -50,9 +50,7 @@ def _require_zstandard(what: str):
 
 
 def _tree_flatten_with_paths(tree):
-    # jax.tree.flatten_with_path only exists in newer jax; use the stable
-    # tree_util spelling so the pinned CI version works too.
-    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     paths = ['/'.join(str(getattr(k, 'key', getattr(k, 'idx', k)))
                       for k in path) for path, _ in flat]
     leaves = [leaf for _, leaf in flat]

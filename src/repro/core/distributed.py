@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import ml_dtypes
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from . import counts as _counts
 from ..data import rowblocks as _rowblocks
@@ -72,6 +72,20 @@ def validate_sharded_loss(loss: str) -> None:
             f'{SHARDED_LOSSES}, got {loss!r}; train this loss with '
             "method='tree'/'pairs'/'auto'/'stream' instead (the fused and "
             'streaming oracles implement every loss in oracle.LOSSES)')
+
+
+def validate_mesh_axes(mesh) -> None:
+    """Reject a mesh with `Explicit` axes, up front: the mesh bodies place
+    their arrays with `with_sharding_constraint`, which accepts only
+    `Auto` axes (and `jax.make_mesh` makes `Explicit` ones by default)."""
+    explicit = [name for name, kind in zip(mesh.axis_names, mesh.axis_types)
+                if kind == AxisType.Explicit]
+    if explicit:
+        raise ValueError(
+            f'the sharded oracle needs Auto mesh axes, but {explicit} are '
+            'Explicit; build the mesh with repro.launch.mesh.make_mesh, or '
+            'pass axis_types=(jax.sharding.AxisType.Auto, ...) to '
+            'jax.make_mesh')
 
 
 @dataclasses.dataclass(frozen=True)

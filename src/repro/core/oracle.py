@@ -999,6 +999,8 @@ class ShardedOracle(RankOracle):
         # densify, padding, or device transfer below touches X.
         _validate_loss(loss)
         _dist.validate_sharded_loss(loss)
+        if mesh is not None:
+            _dist.validate_mesh_axes(mesh)
         self.loss = loss
         _counts._validate_engine(engine)
         _validate_prefetch(prefetch)

@@ -31,15 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# jax >= 0.6 exposes jax.shard_map (replication check kw: check_vma); the
-# pinned 0.4.x series has it under experimental with check_rep instead.
-if hasattr(jax, 'shard_map'):
-    _shard_map = jax.shard_map
-    _CHECK_KW = 'check_vma'
-else:  # pragma: no cover - exercised on the pinned CI/toolchain version
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = 'check_rep'
-
 f32 = jnp.float32
 
 
@@ -118,11 +109,11 @@ def compressed_mean(stacked_tree, mesh, axis: str = 'data',
         return tuple(outs) + tuple(nerrs)
 
     spec = P(axis)                           # leading replica dim sharded
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=tuple(spec for _ in range(2 * len(leaves))),
         out_specs=tuple(spec for _ in range(2 * len(leaves))),
-        **{_CHECK_KW: False})
+        check_vma=False)
     res = fn(*leaves, *errs)
     outs = list(res[:len(leaves)])
     nerrs = list(res[len(leaves):])

@@ -7,15 +7,22 @@ for m <= a few thousand the dense 8x128-lane compare+reduce beats the
 gather-bound merge-sort-tree queries of core.counts (see DESIGN.md §2 and
 benchmarks/fig5_crossover.py).
 
-Tiling: grid (m/TI, m/TJ); each step loads a (TI,) slice of queries i and a
-(TJ,) slice of candidates j, forms the (TI, TJ) comparison tile in registers
-(fp32 VPU ops), reduces over j, and accumulates into the (TI,) outputs.
-TPU grids iterate the trailing axis sequentially, so the j-axis accumulation
-into the i-indexed output block is the canonical revisiting pattern.
+Tiling: grid (m/TI, m/TJ); each step compares a tile of TI queries i with
+TJ candidates j, reduces over j, and accumulates into the i-indexed output
+block. TPU grids iterate the trailing axis sequentially, so the j-axis
+accumulation into the i-indexed output block is the canonical revisiting
+pattern.
 
-Inputs are reshaped to (m/128, 128) so every VMEM block is a hardware-aligned
-(rows, 128) tile. Padding convention (see ops.py): p_pad = +inf, y_pad = +inf
-never contributes to either count.
+Layouts (what the v5e compiler accepts): queries arrive as lane-dense
+(ti_rows, 128) blocks of the (m/128, 128) scores and are turned into a
+(128, ti_rows, 1) column IN the kernel (`query_column`: a 2-D transpose
+plus a unit minor axis); candidates arrive as (1, TJ) blocks of the (1, m)
+scores and broadcast as (1, 1, TJ). The (128, ti_rows, TJ) compare thus
+broadcasts without flattening a (rows, 128) tile to 1-D, which Mosaic
+rejects ("unsupported shape cast"), and without padding a query column's
+lanes in HBM. ti_rows must be a multiple of 8 on the chip (the f32
+sublane tile); any value runs in interpret mode. Padding convention (see
+ops.py): p_pad = +inf, y_pad = +inf never contributes to either count.
 """
 
 from __future__ import annotations
@@ -27,6 +34,15 @@ from jax.experimental import pallas as pl
 LANES = 128
 
 
+def query_column(tile_ref) -> jnp.ndarray:
+    """A lane-dense (rows, 128) query tile as a (128, rows, 1) column.
+
+    Element [l, r, 0] is query r * 128 + l, so a (128, rows) reduction
+    over the candidate axis transposes back (`.T`) to the tile's own
+    (rows, 128) order."""
+    return tile_ref[...].T[:, :, None]
+
+
 def _pairwise_kernel(p_i_ref, y_i_ref, p_j_ref, y_j_ref, c_ref, d_ref):
     j = pl.program_id(1)
 
@@ -35,59 +51,49 @@ def _pairwise_kernel(p_i_ref, y_i_ref, p_j_ref, y_j_ref, c_ref, d_ref):
         c_ref[...] = jnp.zeros_like(c_ref)
         d_ref[...] = jnp.zeros_like(d_ref)
 
-    # (TI_ROWS, 128) query tile flattened to (TI,) vs (TJ,) candidate tile.
-    p_i = p_i_ref[...].reshape(-1)   # (TI,)
-    y_i = y_i_ref[...].reshape(-1)
-    p_j = p_j_ref[...].reshape(-1)   # (TJ,)
-    y_j = y_j_ref[...].reshape(-1)
+    p_i = query_column(p_i_ref)       # (128, TI_ROWS, 1)
+    y_i = query_column(y_i_ref)
+    p_j = p_j_ref[...][None]          # (1, 1, TJ)
+    y_j = y_j_ref[...][None]
 
     # c_i += |{j : y_j > y_i  and  p_j < p_i + 1}|
-    y_gt = y_j[None, :] > y_i[:, None]
-    in_margin_c = p_j[None, :] < p_i[:, None] + 1.0
-    c_tile = jnp.sum(jnp.logical_and(y_gt, in_margin_c), axis=1,
+    c_tile = jnp.sum((y_j > y_i) & (p_j < p_i + 1.0), axis=2,
                      dtype=jnp.int32)
     # d_i += |{j : y_j < y_i  and  p_j > p_i - 1}|
-    y_lt = y_j[None, :] < y_i[:, None]
-    in_margin_d = p_j[None, :] > p_i[:, None] - 1.0
-    d_tile = jnp.sum(jnp.logical_and(y_lt, in_margin_d), axis=1,
+    d_tile = jnp.sum((y_j < y_i) & (p_j > p_i - 1.0), axis=2,
                      dtype=jnp.int32)
 
-    c_ref[...] += c_tile.reshape(c_ref.shape)
-    d_ref[...] += d_tile.reshape(d_ref.shape)
+    c_ref[...] += c_tile.T
+    d_ref[...] += d_tile.T
 
 
 def pairwise_counts_kernel(p2: jnp.ndarray, y2: jnp.ndarray,
-                           ti_rows: int = 2, tj_rows: int = 8,
+                           ti_rows: int = 8, tj_rows: int = 8,
                            interpret: bool = True):
     """Raw pallas_call on pre-padded (rows, 128) inputs.
 
     Args:
       p2, y2: (R, 128) float32, R % max(ti_rows, tj_rows) == 0.
-      ti_rows / tj_rows: VMEM tile heights for the query/candidate axes.
-        Defaults: (2*128) x (8*128) = 256 x 1024 comparison tile = 256 KiB of
-        fp32 intermediates, comfortably inside the ~16 MiB v5e VMEM along with
-        the operand slices.
+      ti_rows / tj_rows: query / candidate tile heights in 128-lane rows.
+        Defaults (8, 8): a (1024, 1024) comparison per grid step, held
+        as (128, 8, 1024) masks, for which the v5e compiler reports
+        1.6 MiB of scoped VMEM (DESIGN.md §8).
       interpret: run the kernel body in Python (CPU validation mode).
     """
     rows = p2.shape[0]
+    tj = tj_rows * LANES
     grid = (rows // ti_rows, rows // tj_rows)
+    query = pl.BlockSpec((ti_rows, LANES), lambda i, j: (i, 0))
+    cand = pl.BlockSpec((1, tj), lambda i, j: (0, j))
     c2, d2 = pl.pallas_call(
         _pairwise_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((ti_rows, LANES), lambda i, j: (i, 0)),
-            pl.BlockSpec((ti_rows, LANES), lambda i, j: (i, 0)),
-            pl.BlockSpec((tj_rows, LANES), lambda i, j: (j, 0)),
-            pl.BlockSpec((tj_rows, LANES), lambda i, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((ti_rows, LANES), lambda i, j: (i, 0)),
-            pl.BlockSpec((ti_rows, LANES), lambda i, j: (i, 0)),
-        ],
+        in_specs=[query, query, cand, cand],
+        out_specs=[query, query],
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
             jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         ],
         interpret=interpret,
-    )(p2, y2, p2, y2)
+    )(p2, y2, p2.reshape(1, -1), y2.reshape(1, -1))
     return c2, d2

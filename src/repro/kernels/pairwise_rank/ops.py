@@ -14,7 +14,7 @@ from ..platform import on_tpu as _on_tpu
 @functools.partial(jax.jit,
                    static_argnames=('ti_rows', 'tj_rows', 'interpret'))
 def pairwise_counts(p: jnp.ndarray, y: jnp.ndarray,
-                    ti_rows: int = 2, tj_rows: int = 8,
+                    ti_rows: int = 8, tj_rows: int = 8,
                     interpret: bool | None = None):
     """O(m^2) (c, d) counts via the tiled Pallas kernel.
 

@@ -38,10 +38,21 @@ chip, vs the O(m^2) of the pairwise kernel; a tie-free worst case
 (every frontier boundary mid-tile) degrades the band term to one dense
 tile row per query tile, never to a full pairwise pass.
 
-Grid: 1-D over query tiles; the candidate arrays and the histogram stay
-whole in VMEM (f32+i32 rows plus the (tiles+1, levels) i32 prefix —
-~10 MB at m = 1e6 with 256 levels, inside a v5e's ~16 MiB VMEM; see
-DESIGN.md §8 for the budget).
+Grid: 1-D over query tiles. Layouts (what the v5e compiler accepts):
+query tiles arrive as lane-dense (ti_rows, 128) blocks and become
+(128, ti_rows, 1) columns in the kernel (`pairwise_rank.kernel.
+query_column`); the candidate scores and ranks are (m/TJ, TJ) arrays
+read one (1, TJ) candidate tile per band step (a dynamic row slice), and
+the histogram rows broadcast as (1, 1, levels). Nothing flattens a
+(rows, 128) tile to 1-D. The candidate arrays and the (tiles+1, levels)
+prefix stay whole in VMEM, single-buffered because their block never
+moves: at m = 2^20 with TJ = 1024 and 256 levels that is 4 MiB of f32
+scores, 4 MiB of i32 ranks and 1 MiB of prefix. For the tiles and the
+band-compare temporaries the v5e compiler reports 2.4 MiB of
+kernel-scoped VMEM when XLA has already placed those operands in VMEM,
+and 6.3 MiB inside the full `rank_counts` program; `_vmem_limit` sizes
+the scoped limit from the resident bytes so the kernel compiles either
+way (DESIGN.md §8).
 """
 
 from __future__ import annotations
@@ -53,63 +64,72 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..pairwise_rank.kernel import query_column
+
 LANES = 128
+# v5e has 128 MiB of VMEM per core; the scoped default is 16 MiB.
+_VMEM_CAP = 100 * 1024 * 1024
 
 
 def _rank_counts_kernel(band_ref, ps_q_ref, yr_q_ref, ps_all_ref,
                         yr_all_ref, pref_ref, c_ref, d_ref, *,
-                        tj_rows: int, levels: int):
+                        levels: int):
     i = pl.program_id(0)
-    ps_q = ps_q_ref[...].reshape(-1)          # (TI,) sorted query scores
-    yr_q = yr_q_ref[...].reshape(-1)          # (TI,) their y-ranks
+    ps_q = query_column(ps_q_ref)      # (128, TI_ROWS, 1) sorted scores
+    yr_q = query_column(yr_q_ref)      # their y-ranks
 
     # Per-query-tile candidate-tile band [lo, hi) for each frontier,
     # prefetched to SMEM: tiles < c_lo are fully inside the p+1 frontier
     # of EVERY query in this tile, tiles >= d_hi fully inside the p-1
     # frontier; the partial bands are compared densely below.
-    c_lo = band_ref[i, 0]
-    c_hi = band_ref[i, 1]
-    d_lo = band_ref[i, 2]
-    d_hi = band_ref[i, 3]
+    c_lo = band_ref[4 * i]
+    c_hi = band_ref[4 * i + 1]
+    d_lo = band_ref[4 * i + 2]
+    d_hi = band_ref[4 * i + 3]
 
-    lvl = jax.lax.broadcasted_iota(jnp.int32, (1, levels), 1)
+    def pref_row(t):
+        return pref_ref[pl.ds(t, 1), :][None]      # (1, 1, levels)
+
+    lvl = jax.lax.broadcasted_iota(jnp.int32, (1, 1, levels), 2)
     # c prefix: candidates in tiles [0, c_lo), counted by y-level.
-    p_c = pl.load(pref_ref, (pl.ds(c_lo, 1), slice(None)))     # (1, levels)
-    c_acc = jnp.sum(jnp.where(lvl > yr_q[:, None], p_c, 0), axis=1,
+    c_acc = jnp.sum(jnp.where(lvl > yr_q, pref_row(c_lo), 0), axis=2,
                     dtype=jnp.int32)
     # d suffix: candidates in tiles [d_hi, nJ) = total minus prefix —
     # the complement trick, answered from the SAME histogram.
-    p_top = pref_ref[pref_ref.shape[0] - 1, :][None, :]
-    p_d = p_top - pl.load(pref_ref, (pl.ds(d_hi, 1), slice(None)))
-    d_acc = jnp.sum(jnp.where(lvl < yr_q[:, None], p_d, 0), axis=1,
-                    dtype=jnp.int32)
+    p_d = pref_row(pref_ref.shape[0] - 1) - pref_row(d_hi)
+    d_acc = jnp.sum(jnp.where(lvl < yr_q, p_d, 0), axis=2, dtype=jnp.int32)
 
-    # Partial bands: the reference comparisons, one (TI, TJ) tile at a
-    # time over dynamically-bounded tile ranges.
+    # Partial bands: the reference comparisons, one (1, TJ) candidate
+    # tile at a time over dynamically-bounded tile ranges.
     def c_body(j, acc):
-        ps_j = pl.load(ps_all_ref, (pl.ds(j * tj_rows, tj_rows),
-                                    slice(None))).reshape(-1)
-        yr_j = pl.load(yr_all_ref, (pl.ds(j * tj_rows, tj_rows),
-                                    slice(None))).reshape(-1)
-        hit = ((yr_j[None, :] > yr_q[:, None])
-               & (ps_j[None, :] < ps_q[:, None] + 1.0))
-        return acc + jnp.sum(hit, axis=1, dtype=jnp.int32)
+        ps_j = ps_all_ref[pl.ds(j, 1), :][None]    # (1, 1, TJ)
+        yr_j = yr_all_ref[pl.ds(j, 1), :][None]
+        hit = (yr_j > yr_q) & (ps_j < ps_q + 1.0)
+        return acc + jnp.sum(hit, axis=2, dtype=jnp.int32)
 
     c_acc = jax.lax.fori_loop(c_lo, c_hi, c_body, c_acc)
 
     def d_body(j, acc):
-        ps_j = pl.load(ps_all_ref, (pl.ds(j * tj_rows, tj_rows),
-                                    slice(None))).reshape(-1)
-        yr_j = pl.load(yr_all_ref, (pl.ds(j * tj_rows, tj_rows),
-                                    slice(None))).reshape(-1)
-        hit = ((yr_j[None, :] < yr_q[:, None])
-               & (ps_j[None, :] > ps_q[:, None] - 1.0))
-        return acc + jnp.sum(hit, axis=1, dtype=jnp.int32)
+        ps_j = ps_all_ref[pl.ds(j, 1), :][None]
+        yr_j = yr_all_ref[pl.ds(j, 1), :][None]
+        hit = (yr_j < yr_q) & (ps_j > ps_q - 1.0)
+        return acc + jnp.sum(hit, axis=2, dtype=jnp.int32)
 
     d_acc = jax.lax.fori_loop(d_lo, d_hi, d_body, d_acc)
 
-    c_ref[...] = c_acc.reshape(c_ref.shape)
-    d_ref[...] = d_acc.reshape(d_ref.shape)
+    c_ref[...] = c_acc.T
+    d_ref[...] = d_acc.T
+
+
+def _vmem_limit(n_tiles: int, tj: int, levels: int) -> int:
+    """Scoped-VMEM limit for one call: the whole-resident candidate
+    arrays and prefix (single-buffered), plus 8 MiB for the pipelined
+    query tiles and the band-compare temporaries (the v5e compiler
+    reports 2.4-6.3 MiB of scoped VMEM for those at the default tiles,
+    m = 2^20); never below the 16 MiB default, capped under the
+    128 MiB of the core."""
+    resident = 4 * (2 * n_tiles * tj + (n_tiles + 1) * levels)
+    return int(min(max(resident + (8 << 20), 16 << 20), _VMEM_CAP))
 
 
 def rank_counts_kernel(band: jnp.ndarray, ps2: jnp.ndarray,
@@ -119,44 +139,50 @@ def rank_counts_kernel(band: jnp.ndarray, ps2: jnp.ndarray,
     """Raw pallas_call on pre-sorted, pre-padded (rows, 128) inputs.
 
     Args:
-      band: (rows/ti_rows, 4) int32 per-query-tile candidate-tile bands
-        [c_lo, c_hi, d_lo, d_hi] (scalar-prefetched to SMEM).
+      band: (4 * rows/ti_rows,) int32 per-query-tile candidate-tile
+        bands [c_lo, c_hi, d_lo, d_hi], flattened (scalar-prefetched to
+        SMEM, where a 2-D (tiles, 4) array would pad its minor axis to
+        128 words and overflow SMEM's 1 MiB past m = 2^21).
       ps2: (R, 128) float32 scores in ascending order, padded with +inf;
         R % max(ti_rows, tj_rows) == 0.
       yr2: (R, 128) int32 compact y-ranks in the same order, pads
         = `levels` (one past any real rank).
       pref: (R/tj_rows + 1, levels) int32 cumulative per-candidate-tile
         y-level histogram; row t counts tiles [0, t), pads excluded.
-      ti_rows / tj_rows: VMEM tile heights for the query/candidate axes.
-        Defaults (8, 8): 1024-element tiles, whose (TI, TJ) dense-band
-        compare is 4 MiB of f32 intermediates.
+      ti_rows / tj_rows: query / candidate tile heights in 128-lane rows
+        (ti_rows a multiple of 8 on the chip). Defaults (8, 8):
+        1024-element tiles, whose dense-band compare is a
+        (128, 8, 1024) mask.
       interpret: run the kernel body in Python (CPU validation mode).
     """
     rows = ps2.shape[0]
     levels = pref.shape[1]
+    tj = tj_rows * LANES
+    n_tiles = rows // tj_rows
+    query = pl.BlockSpec((ti_rows, LANES), lambda i, band: (i, 0))
+
+    def whole(shape):
+        # The block never moves, so one buffer is enough.
+        return pl.BlockSpec(shape, lambda i, band: (0, 0),
+                            pipeline_mode=pl.Buffered(1))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(rows // ti_rows,),
-        in_specs=[
-            pl.BlockSpec((ti_rows, LANES), lambda i, band: (i, 0)),
-            pl.BlockSpec((ti_rows, LANES), lambda i, band: (i, 0)),
-            pl.BlockSpec((rows, LANES), lambda i, band: (0, 0)),
-            pl.BlockSpec((rows, LANES), lambda i, band: (0, 0)),
-            pl.BlockSpec(pref.shape, lambda i, band: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((ti_rows, LANES), lambda i, band: (i, 0)),
-            pl.BlockSpec((ti_rows, LANES), lambda i, band: (i, 0)),
-        ],
+        in_specs=[query, query, whole((n_tiles, tj)), whole((n_tiles, tj)),
+                  whole(pref.shape)],
+        out_specs=[query, query],
     )
     c2, d2 = pl.pallas_call(
-        functools.partial(_rank_counts_kernel, tj_rows=tj_rows,
-                          levels=levels),
+        functools.partial(_rank_counts_kernel, levels=levels),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
             jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(n_tiles, tj, levels)),
         interpret=interpret,
-    )(band, ps2, yr2, ps2, yr2, pref)
+    )(band, ps2, yr2, ps2.reshape(n_tiles, tj), yr2.reshape(n_tiles, tj),
+      pref)
     return c2, d2

@@ -86,7 +86,7 @@ def _kernel_counts(p, y, ti_rows: int, tj_rows: int, levels: int,
     r_min = jnp.searchsorted(ps_pad, q0 - one, side='right').astype(jnp.int32)
     r_max = jnp.searchsorted(ps_pad, q1 - one, side='right').astype(jnp.int32)
     band = jnp.stack([l_min // tj, -(-l_max // tj),
-                      r_min // tj, -(-r_max // tj)], axis=1)
+                      r_min // tj, -(-r_max // tj)], axis=1).reshape(-1)
 
     c2, d2 = _k.rank_counts_kernel(band, ps_pad.reshape(-1, _k.LANES),
                                    yr_pad.reshape(-1, _k.LANES), pref,

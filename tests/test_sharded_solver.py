@@ -132,6 +132,16 @@ def test_sharded_many_groups_precision_warns():
         O.ShardedOracle(X, y, groups=g)
 
 
+def test_sharded_rejects_explicit_mesh_axes():
+    """`jax.make_mesh` gives Explicit axes by default; the mesh bodies'
+    sharding constraints need Auto ones, so the oracle refuses such a
+    mesh at construction and names the helper that builds the right one."""
+    mesh = jax.make_mesh((jax.device_count(), 1), ('data', 'model'))
+    X, y, groups, _ = _grouped_case()
+    with pytest.raises(ValueError, match='launch.mesh.make_mesh'):
+        O.ShardedOracle(X, y, groups=groups, mesh=mesh)
+
+
 def test_empty_grouped_input_keeps_clean_no_pairs_error():
     """m=0 with groups must still raise the actionable no-pairs error,
     not a numpy reduction crash in the key-scale warning."""

@@ -11,12 +11,11 @@ from repro.models.params import param_specs
 
 
 def mesh2(data=16, model=16):
-    # name/size pairs: the AbstractMesh signature in the pinned jax
-    return AbstractMesh((('data', data), ('model', model)))
+    return AbstractMesh((data, model), ('data', 'model'))
 
 
 def mesh3(pod=2, data=16, model=16):
-    return AbstractMesh((('pod', pod), ('data', data), ('model', model)))
+    return AbstractMesh((pod, data, model), ('pod', 'data', 'model'))
 
 
 def test_basic_rules():

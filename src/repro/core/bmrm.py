@@ -115,12 +115,12 @@ class BMRMStats:
     loss_history: list
     gap_history: list
     oracle_seconds: list  # host: per-iteration oracle wall time;
-    # device: amortized fused-step (oracle+QP) time per iteration. Either
-    # way wall-clock truth: on a cold fit the first entry (host) / first
-    # chunk's entries (device) include one-time jit trace+compile — warm
-    # the oracle (or compare second fits, as the benchmarks do) for
-    # steady-state numbers.
-    qp_seconds: list      # host driver only; fused into the step on device
+    # device: the chunk's wall time (dispatch, its fused steps of oracle,
+    # plane insert and QP, and the host sync that reads its results)
+    # amortized over the chunk's steps. Either way wall-clock truth: on a
+    # cold fit the first entry (host) / first chunk's entries (device)
+    # include one-time jit trace+compile — warm the oracle (or compare
+    # second fits, as the benchmarks do) for steady-state numbers.
     solver: str = 'host'
     seconds: float = float('nan')  # wall-clock of the fit; filled by
     # `bmrm_path` (for mode='vmap' each lambda gets its share of the one
@@ -268,7 +268,7 @@ def _bmrm_host(fn, dim, device, lam, eps, max_iter, w0, max_planes,
     # J at the starting point (evaluated inside the first loop turn).
     w_best = w_prev if device else w_prev.copy()
     j_best = np.inf
-    stats = BMRMStats(0, False, np.inf, np.inf, [], [], [], [],
+    stats = BMRMStats(0, False, np.inf, np.inf, [], [], [],
                       solver='host')
 
     for t in range(1, max_iter + 1):
@@ -317,12 +317,10 @@ def _bmrm_host(fn, dim, device, lam, eps, max_iter, w0, max_planes,
             else:
                 A = A[keep]
 
-        t1 = time.perf_counter()
         warm = None
         if alpha is not None and len(alpha) == len(bvec) - 1:
             warm = np.append(alpha * (1.0 - 1e-3), 1e-3)
         alpha, dual_val = solve_bundle_dual(G, bvec, lam, alpha0=warm)
-        stats.qp_seconds.append(time.perf_counter() - t1)
 
         w_t = -(A.T @ (jnp.asarray(alpha, jnp.float32) if device
                        else alpha)) / (2.0 * lam)
@@ -489,47 +487,54 @@ def abstract_bundle_state(dim: int, max_planes: int) -> BundleState:
 
 
 def _bundle_step(s: BundleState, step_fn, lam, eps, qp_iters: int):
-    """ONE fully-traced BMRM iteration over the fixed-capacity state."""
+    """ONE fully-traced BMRM iteration over the fixed-capacity state.
+
+    After the oracle step, its work runs under two named scopes: the
+    best-iterate bookkeeping and plane insert under 'plane_insert', the
+    QP, the iterate and the gap under 'qp'."""
     K = s.b.shape[0]
     r_emp, a = step_fn(s.w)
     r_emp = r_emp.astype(f32)
     a = a.astype(f32)
 
-    wa = s.w @ a
-    j_prev = r_emp + lam * (s.w @ s.w)
-    better = j_prev < s.j_best
-    j_best = jnp.where(better, j_prev, s.j_best)
-    w_best = jnp.where(better, s.w, s.w_best)
+    with jax.named_scope('plane_insert'):
+        wa = s.w @ a
+        j_prev = r_emp + lam * (s.w @ s.w)
+        better = j_prev < s.j_best
+        j_best = jnp.where(better, j_prev, s.j_best)
+        w_best = jnp.where(better, s.w, s.w_best)
 
-    # Insert slot: next free, or (buffer full) the least-active plane.
-    idx = jnp.arange(K, dtype=jnp.int32)
-    full = s.n_active >= K
-    masked_alpha = jnp.where(idx < s.n_active, s.alpha, jnp.inf)
-    slot = jnp.where(full, jnp.argmin(masked_alpha).astype(jnp.int32),
-                     s.n_active)
-    A = jax.lax.dynamic_update_slice(s.A, a[None, :], (slot, 0))
-    # The slot's support iterate: the plane just inserted is R_emp's
-    # tangent at s.w — recorded so data warm starts (core.incremental)
-    # can revalidate the plane for appended rows at exactly this point.
-    S = jax.lax.dynamic_update_slice(s.S, s.w[None, :], (slot, 0))
-    cross = A @ a                    # rows >= n_active are zero-filled
-    G = s.G.at[slot, :].set(cross).at[:, slot].set(cross)
-    b = s.b.at[slot].set(r_emp - wa)
-    n_active = jnp.minimum(s.n_active + 1, K)
-    mask = idx < n_active
+        # Insert slot: next free, or (buffer full) the least-active plane.
+        idx = jnp.arange(K, dtype=jnp.int32)
+        full = s.n_active >= K
+        masked_alpha = jnp.where(idx < s.n_active, s.alpha, jnp.inf)
+        slot = jnp.where(full, jnp.argmin(masked_alpha).astype(jnp.int32),
+                         s.n_active)
+        A = jax.lax.dynamic_update_slice(s.A, a[None, :], (slot, 0))
+        # The slot's support iterate: the plane just inserted is R_emp's
+        # tangent at s.w — recorded so data warm starts (core.incremental)
+        # can revalidate the plane for appended rows at exactly this point.
+        S = jax.lax.dynamic_update_slice(s.S, s.w[None, :], (slot, 0))
+        cross = A @ a                    # rows >= n_active are zero-filled
+        G = s.G.at[slot, :].set(cross).at[:, slot].set(cross)
+        b = s.b.at[slot].set(r_emp - wa)
+        n_active = jnp.minimum(s.n_active + 1, K)
+        mask = idx < n_active
 
-    # Warm-started masked QP; the new plane enters with a small weight and
-    # the projection inside the solver renormalizes onto the simplex.
-    alpha0 = s.alpha.at[slot].set(1e-3)
-    alpha, dual = solve_bundle_dual_jax(G, b, lam, mask, alpha0=alpha0,
-                                        n_iter=qp_iters)
-    w = -(A.T @ alpha) / (2.0 * lam)
+    with jax.named_scope('qp'):
+        # Warm-started masked QP; the new plane enters with a small weight
+        # and the projection inside the solver renormalizes onto the
+        # simplex.
+        alpha0 = s.alpha.at[slot].set(1e-3)
+        alpha, dual = solve_bundle_dual_jax(G, b, lam, mask, alpha0=alpha0,
+                                            n_iter=qp_iters)
+        w = -(A.T @ alpha) / (2.0 * lam)
 
-    # Gap against the DUAL value: D(alpha) <= min_w J_t(w) for any feasible
-    # alpha, so an under-converged QP inflates the gap instead of faking
-    # convergence.
-    gap = j_best - dual
-    done = s.done | (gap < eps)
+        # Gap against the DUAL value: D(alpha) <= min_w J_t(w) for any
+        # feasible alpha, so an under-converged QP inflates the gap instead
+        # of faking convergence.
+        gap = j_best - dual
+        done = s.done | (gap < eps)
     return BundleState(w=w, w_best=w_best, j_best=j_best, A=A, b=b, G=G,
                        alpha=alpha, n_active=n_active, gap=gap,
                        done=done, S=S), r_emp
@@ -682,41 +687,51 @@ def _bmrm_device(oracle, dim, lam, eps, max_iter, w0, max_planes, callback,
 
     lam_d = jnp.asarray(lam, f32)
     eps_d = jnp.asarray(eps, f32)
-    stats = BMRMStats(0, False, np.inf, np.inf, [], [], [], [],
+    stats = BMRMStats(0, False, np.inf, np.inf, [], [], [],
                       solver='device')
 
     # Fit-local chunk cache: bounds compiles to the distinct chunk lengths
     # even for non-weakrefable oracles (where _CHUNK_CACHE can't help).
     chunks: dict = {}
+    # Host spans, for traces: 'bmrm.dispatch' around the chunk call,
+    # 'bmrm.sync' around every device-to-host read of the chunk (and, after
+    # the last chunk, of the fit's end).
     while True:                       # always >= 1 chunk (matches ceil())
         chunk = chunks.get(cur_sync)
         if chunk is None:
             chunk = _device_chunk(oracle, K, cur_sync, qp_iters)
             chunks[cur_sync] = chunk
         t0 = time.perf_counter()
-        state, (losses, gaps, valids) = chunk(state, lam_d, eps_d)
-        v = np.asarray(valids)               # the one sync point per chunk
-        dt = time.perf_counter() - t0
-        steps = int(v.sum())
-        gaps = np.asarray(gaps, np.float64)[v]
+        with jax.profiler.TraceAnnotation('bmrm.dispatch'):
+            state, (losses, gaps, valids) = chunk(state, lam_d, eps_d)
+        with jax.profiler.TraceAnnotation('bmrm.sync'):
+            v = np.asarray(valids)           # the one sync point per chunk
+            dt = time.perf_counter() - t0
+            steps = int(v.sum())
+            losses = np.asarray(losses, np.float64)[v]
+            gaps = np.asarray(gaps, np.float64)[v]
+            done = bool(state.done)
+            last = done or stats.iterations + steps >= max_iter
+            if callback is not None or last:
+                j_best, gap = float(state.j_best), float(state.gap)
+            if last:
+                w_best = np.asarray(state.w_best, np.float64)
         if steps:
-            stats.loss_history.extend(np.asarray(losses, np.float64)[v])
+            stats.loss_history.extend(losses)
             stats.gap_history.extend(gaps)
             stats.oracle_seconds.extend([dt / steps] * steps)
             stats.iterations += steps
         if callback is not None:
-            callback(stats.iterations, state.w, float(state.j_best),
-                     float(state.gap))
-        if bool(state.done) or stats.iterations >= max_iter:
+            callback(stats.iterations, state.w, j_best, gap)
+        if last:
             break
         if auto_sync:
             cur_sync = _next_sync_every(gaps, eps, cur_sync)
 
-    stats.converged = bool(state.done)
-    stats.obj_best = float(state.j_best)
-    stats.gap = float(state.gap)
-    return BMRMResult(w=np.asarray(state.w_best, np.float64), stats=stats,
-                      state=state)
+    stats.converged = done
+    stats.obj_best = j_best
+    stats.gap = gap
+    return BMRMResult(w=w_best, stats=stats, state=state)
 
 
 # ------------------------------------------------------ batched path sweep
@@ -980,7 +995,7 @@ def _bmrm_path_vmap(oracle, lams, dim, eps, max_iter, w0, max_planes,
             iterations=int(iters[k]), converged=bool(done[k]),
             obj_best=float(j_best[k]), gap=float(gap[k]),
             loss_history=loss_hist[k], gap_history=gap_hist[k],
-            oracle_seconds=secs[k], qp_seconds=[], solver='vmap',
+            oracle_seconds=secs[k], solver='vmap',
             seconds=float(np.sum(secs[k])))
         state_k = jax.tree_util.tree_map(lambda x, k=k: x[k], state)
         results.append(BMRMResult(w=w_best[k], stats=stats, state=state_k))

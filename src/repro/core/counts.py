@@ -256,32 +256,40 @@ def counts_fused(p: jnp.ndarray, y: jnp.ndarray):
     against the same rounded f32 value p_i - 1), so tie semantics match the
     O(m^2) oracle bit-for-bit. Same O(m log^2 m) work bound, ~half the
     constant: the tree build (the log^2 sort term) happens once.
+
+    Its four phases run under the named scopes 'sort', 'tree', 'query'
+    and 'unsort', which name their operations in traces.
     """
     p = p.astype(jnp.float32) if p.dtype == jnp.float64 else p
     m = p.shape[0]
     if m == 0:
         z = jnp.zeros((0,), jnp.int32)
         return z, z
-    order = jnp.argsort(p)
-    ps = jnp.take(p, order)
-    ys = jnp.take(y, order)
-    mpad = _next_pow2(m)
-    y_pad = jnp.pad(ys, (0, mpad - m), constant_values=jnp.inf)
-    levels = _tree_levels(y_pad)
+    with jax.named_scope('sort'):
+        order = jnp.argsort(p)
+        ps = jnp.take(p, order)
+        ys = jnp.take(y, order)
+    with jax.named_scope('tree'):
+        mpad = _next_pow2(m)
+        y_pad = jnp.pad(ys, (0, mpad - m), constant_values=jnp.inf)
+        levels = _tree_levels(y_pad)
 
-    one = jnp.asarray(1.0, ps.dtype)
-    # c: frontier p_k < p_i + 1, count y_k > y_i inside it.
-    frontier = jnp.searchsorted(ps, ps + one, side='left').astype(jnp.int32)
-    c_sorted = _prefix_query(levels, y_pad, frontier, ys, 'gt')
-    # d: prefix p_k <= p_i - 1, count y_k < y_i inside it; subtract from the
-    # global strict rank of y_i.
-    inner = jnp.searchsorted(ps, ps - one, side='right').astype(jnp.int32)
-    lt_inner = _prefix_query(levels, y_pad, inner, ys, 'lt')
-    glt = jnp.searchsorted(jnp.sort(y), ys, side='left').astype(jnp.int32)
-    d_sorted = glt - lt_inner
+    with jax.named_scope('query'):
+        one = jnp.asarray(1.0, ps.dtype)
+        # c: frontier p_k < p_i + 1, count y_k > y_i inside it.
+        frontier = jnp.searchsorted(ps, ps + one,
+                                    side='left').astype(jnp.int32)
+        c_sorted = _prefix_query(levels, y_pad, frontier, ys, 'gt')
+        # d: prefix p_k <= p_i - 1, count y_k < y_i inside it; subtract from
+        # the global strict rank of y_i.
+        inner = jnp.searchsorted(ps, ps - one, side='right').astype(jnp.int32)
+        lt_inner = _prefix_query(levels, y_pad, inner, ys, 'lt')
+        glt = jnp.searchsorted(jnp.sort(y), ys, side='left').astype(jnp.int32)
+        d_sorted = glt - lt_inner
 
-    z = jnp.zeros((m,), jnp.int32)
-    return z.at[order].set(c_sorted), z.at[order].set(d_sorted)
+    with jax.named_scope('unsort'):
+        z = jnp.zeros((m,), jnp.int32)
+        return z.at[order].set(c_sorted), z.at[order].set(d_sorted)
 
 
 @jax.jit

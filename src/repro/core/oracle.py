@@ -471,30 +471,37 @@ def _fused_step_impl(w, arrays, y, g, inv_n, pw=None, *, engine: str,
     for standalone per-call use (`loss_and_subgrad`). When device_rmatvec
     is False the step returns (loss, coeffs) and the caller finishes the
     transpose-matvec on host (see _CSRFeatures). `pw` is the poshinge
-    per-example weight vector (None for the other losses).
+    per-example weight vector (None for the other losses). The three
+    parts run under the named scopes 'matvec', 'counts' and 'rmatvec',
+    which name their operations in the compiled program and its traces.
     """
     m = y.shape[0]
-    if kind == 'dense':
-        p = arrays['X'] @ w
-    elif uniform:
-        p = jnp.sum(arrays['data2'] * w[arrays['idx2']], axis=1)
-    else:
-        p = jax.ops.segment_sum(arrays['data'] * w[arrays['idx']],
-                                arrays['rows'], num_segments=m,
-                                indices_are_sorted=True)
-    loss_val, cd = _loss_and_coeffs(p, y, g, inv_n, pw, engine=engine,
-                                    block=block, loss=loss)
+    with jax.named_scope('matvec'):
+        if kind == 'dense':
+            p = arrays['X'] @ w
+        elif uniform:
+            p = jnp.sum(arrays['data2'] * w[arrays['idx2']], axis=1)
+        else:
+            p = jax.ops.segment_sum(arrays['data'] * w[arrays['idx']],
+                                    arrays['rows'], num_segments=m,
+                                    indices_are_sorted=True)
+    with jax.named_scope('counts'):
+        loss_val, cd = _loss_and_coeffs(p, y, g, inv_n, pw, engine=engine,
+                                        block=block, loss=loss)
     if not device_rmatvec:
         return loss_val, cd                  # host finishes the rmatvec
-    v = cd * inv_n
-    if kind == 'dense':
-        return loss_val, arrays['X'].T @ v
-    if uniform:
-        return loss_val, jax.ops.segment_sum(
-            (arrays['data2'] * v[:, None]).reshape(-1),
-            arrays['idx2'].reshape(-1), num_segments=n)
-    return loss_val, jax.ops.segment_sum(arrays['data'] * v[arrays['rows']],
-                                         arrays['idx'], num_segments=n)
+    with jax.named_scope('rmatvec'):
+        v = cd * inv_n
+        if kind == 'dense':
+            a = arrays['X'].T @ v
+        elif uniform:
+            a = jax.ops.segment_sum(
+                (arrays['data2'] * v[:, None]).reshape(-1),
+                arrays['idx2'].reshape(-1), num_segments=n)
+        else:
+            a = jax.ops.segment_sum(arrays['data'] * v[arrays['rows']],
+                                    arrays['idx'], num_segments=n)
+    return loss_val, a
 
 
 _fused_step = functools.partial(jax.jit, static_argnames=(
@@ -529,7 +536,11 @@ class _FusedOracle(RankOracle):
         if loss != 'hinge':
             self.name = f'{self.name}/{loss}'
         y = np.asarray(y, np.float32)
-        self._feats = _features(X, csr_rmatvec=csr_rmatvec)
+        # Host spans, for traces: the feature and label upload, then the
+        # host pair count.
+        with jax.profiler.TraceAnnotation('oracle.transfer'):
+            self._feats = _features(X, csr_rmatvec=csr_rmatvec)
+            self._y = jnp.asarray(y)
         self.m, self.n = self._feats.m, self._feats.n
         if y.shape[0] != self.m:
             raise ValueError(f'X has {self.m} rows but y has {y.shape[0]}')
@@ -538,10 +549,10 @@ class _FusedOracle(RankOracle):
             # ~1e-3 tolerance: counts.py's ~1e4 key-scale envelope for the
             # f32 oracles.
             _warn_group_key_scale(groups, y, tol=1e-3, stacklevel=4)
-        self.n_pairs = _exact_pairs(y, groups)
+        with jax.profiler.TraceAnnotation('oracle.pairs'):
+            self.n_pairs = _exact_pairs(y, groups)
         if self.n_pairs == 0:
             raise ValueError('training data induces no preference pairs')
-        self._y = jnp.asarray(y)
         self._g = None if groups is None else jnp.asarray(groups)
         if loss == 'hinge':
             self.norm, pw = float(self.n_pairs), None

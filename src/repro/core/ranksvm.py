@@ -66,6 +66,7 @@ import time
 import numpy as np
 
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from . import rank_loss as _rank_loss
 from ..data.rowblocks import BlockStore, projected_resident_gib
@@ -247,21 +248,27 @@ class RankSVM:
         the store carries them); either way the fit leaves an
         `incremental_` handle behind, so `refit()` can later append or
         retire row blocks and warm-start from this solution instead of
-        training cold (DESIGN.md §11)."""
-        store, y, groups = self._as_store(X, y, groups)
-        oracle = self._make_oracle(X if not isinstance(X, BlockStore)
-                                   else store, y, groups)
-        self.oracle_ = oracle
+        training cold (DESIGN.md §11).
 
-        t0 = time.perf_counter()
-        res = self._solve(oracle, self.lam)
-        dt = time.perf_counter() - t0
+        In a profiler trace the fit is the host span 'ranksvm.fit', holding
+        'ranksvm.make_oracle' and 'ranksvm.solve'."""
+        with TraceAnnotation('ranksvm.fit'):
+            store, y, groups = self._as_store(X, y, groups)
+            with TraceAnnotation('ranksvm.make_oracle'):
+                oracle = self._make_oracle(
+                    X if not isinstance(X, BlockStore) else store, y, groups)
+            self.oracle_ = oracle
 
-        self.w_ = res.w
-        self.report_ = self._report(res, dt)
-        self.incremental_ = IncrementalFit(store, res.state,
-                                           self._ledger_norm(oracle),
-                                           partials_fn=self._partials)
+            t0 = time.perf_counter()
+            with TraceAnnotation('ranksvm.solve'):
+                res = self._solve(oracle, self.lam)
+            dt = time.perf_counter() - t0
+
+            self.w_ = res.w
+            self.report_ = self._report(res, dt)
+            self.incremental_ = IncrementalFit(store, res.state,
+                                               self._ledger_norm(oracle),
+                                               partials_fn=self._partials)
         return self
 
     def path(self, X, y, lams, groups=None, mode: str = 'auto',

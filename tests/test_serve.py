@@ -332,9 +332,12 @@ def test_hot_swap_single_version_per_response():
     weights = {v: (w0 * float(2 ** v)).astype(np.float32)
                for v in range(13)}
     scorer = Scorer(store)
+    # warm every program the traffic below can hit (candidate buckets up to
+    # 40 rows, k up to 3, coalesced batches up to 8) so in-flight traffic
+    # is fast enough to straddle several swaps: a first compile mid-traffic
+    # outlasts all twelve of them
+    scorer.warm(40, ks=(1, 2, 3), max_batch=8)
     with MicroBatcher(scorer, max_batch=8, max_delay_ms=1.0) as mb:
-        # warm the (bucket 64, k-bucket 4) program so in-flight traffic
-        # is fast enough to straddle several swaps
         mb.submit(np.zeros((40, D), np.float32), 3).result(30.0)
         stop = threading.Event()
         checked = []

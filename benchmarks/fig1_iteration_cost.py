@@ -7,8 +7,8 @@ same two dataset archetypes (dense cadata-like, sparse reuters-like).
 
 Post-refactor this also measures the oracle layer itself: `tree_s` is the
 device-resident `core.oracle.TreeOracle` (one fused jitted step: matvec +
-single-tree counts + loss + subgradient), `tree_host_s` is the pre-refactor
-estimator loop it replaced (host numpy matvecs, two-tree counts, c/d
+counts + loss + subgradient), `tree_host_s` is the pre-refactor
+estimator loop it replaced (host numpy matvecs, device counts, c/d
 round-tripped through the host as float64). The acceptance bar for the
 refactor: tree_s <= tree_host_s at m >= 1e5 on the same hardware.
 """
@@ -40,7 +40,7 @@ def _host_oracle_seconds(X, y, method: str, block: int = 2048) -> float:
         p = X.matvec(w) if hasattr(X, 'matvec') else X @ w
         pj = jnp.asarray(p, jnp.float32)
         if method == 'tree':
-            c, d = C.counts(pj, yj)
+            c, d = C.counts_fused(pj, yj)
         else:
             c, d = C.counts_blocked_host(pj, yj, block=block)
         cd = np.asarray(c, np.float64) - np.asarray(d, np.float64)

@@ -32,7 +32,7 @@ def main(full: bool = False):
         data_mb = (Xm.data.nbytes + Xm.indices.nbytes
                    + Xm.indptr.nbytes) / 1e6
         base = peak_rss_mb()
-        c, d = C.counts(jnp.asarray(Xm.matvec(np.ones(Xm.shape[1])),
+        c, d = C.counts_fused(jnp.asarray(Xm.matvec(np.ones(Xm.shape[1])),
                                     jnp.float32), jnp.asarray(y, jnp.float32))
         c.block_until_ready()
         peak = peak_rss_mb()

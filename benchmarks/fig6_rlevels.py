@@ -28,7 +28,7 @@ def main(full: bool = False):
         yl = jnp.asarray(rng.integers(0, r, size=m).astype(np.int32))
         yv = yl.astype(jnp.float32)
         t_r = timeit(lambda: J.counts_rlevel(p, yl, r)[0].block_until_ready())
-        t_t = timeit(lambda: C.counts(p, yv)[0].block_until_ready())
+        t_t = timeit(lambda: C.counts_fused(p, yv)[0].block_until_ready())
         rep.row(m, r, round(t_r, 5), round(t_t, 5))
     return rep
 

@@ -20,7 +20,7 @@ def _counts_seconds(m: int, method: str, seed: int = 0) -> float:
     y = jnp.asarray(rng.normal(size=m).astype(np.float32))
 
     if method == 'tree':
-        fn = lambda: C.counts(p, y)[0].block_until_ready()
+        fn = lambda: C.counts_fused(p, y)[0].block_until_ready()
     else:
         fn = lambda: C.counts_blocked_host(p, y)[0].block_until_ready()
     return timeit(fn, repeats=3, warmup=1)

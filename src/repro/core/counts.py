@@ -23,9 +23,19 @@ vectorized branchless binary searches:
 Work: O(m log^2 m); depth: O(log m); identical counts to the O(m^2) oracle
 (including the paper's exact strict/non-strict tie semantics).
 
-d is obtained from c by the reflection d(p, y) = c(-p, -y), which is exact in
-floating point (negation is exact and round-to-nearest is odd-symmetric, so
-the margin comparisons match the oracle's bit-for-bit).
+The sharded oracle (core.distributed) searches that tree, `_half_counts`,
+and obtains d from c by the reflection d(p, y) = c(-p, -y), which is exact
+in floating point (negation is exact and round-to-nearest is odd-symmetric,
+so the margin comparisons match the oracle's bit-for-bit). The weighted
+counts search it too.
+
+The binary searches are gathers, the one primitive a TPU does worst. So
+the engine everything else runs, `counts_fused`, answers all queries
+offline instead: c and d are dominance counts over points and queries
+placed in one p-order, and the same merge-sort tree is built bottom-up
+over y-rank with the queries riding through its level sorts. It is sorts,
+cumsums, slices and concatenations with no gather, scatter or search, for
+the same work bound.
 """
 
 from __future__ import annotations
@@ -224,77 +234,152 @@ def _half_counts(p: jnp.ndarray, y: jnp.ndarray,
     return jnp.zeros((m,), jnp.int32).at[order].set(c_sorted)
 
 
-@jax.jit
-def counts(p: jnp.ndarray, y: jnp.ndarray):
-    """Linearithmic computation of the paper's frequency vectors (c, d).
+# Item kinds of `counts_fused`'s offline dominance count. On equal sort
+# keys the ordering sort puts a c-query before a point and a d-query after
+# it. Its uint32 level keys hold at most `_DOMINANCE_MAX_ITEMS` items.
+_C_QUERY, _POINT, _D_QUERY, _PAD = 0, 1, 2, 3
+_DOMINANCE_MAX_ITEMS = 1 << 29
+# A TPU tile is 8 sublanes by 128 lanes. A row-major (blocks, block) array
+# pads each row of a block under 128 items to 128 lanes, and a sort over
+# fewer than 8 rows runs on part-filled tiles (the top three levels took
+# 60 of 125 ms at 2^22 items on a v5e).
+_TILE_ROWS, _LANES = 8, 128
 
-    Bit-identical to `ref.counts_ref` for any real-valued p, y (ties included).
-    """
-    p = p.astype(jnp.float32) if p.dtype == jnp.float64 else p
-    c = _half_counts(p, y)
-    # Reflection: d_i = |{j : y_j < y_i and p_j > p_i - 1}| = c(-p, -y)_i.
-    d = _half_counts(-p, -y)
-    return c, d
+
+def _bit_reverse(x: jnp.ndarray, bits: int) -> jnp.ndarray:
+    """x with its low `bits` bits in reverse order."""
+    r = jnp.zeros_like(x)
+    for k in range(bits):
+        r = r | ((x >> k) & 1) << (bits - 1 - k)
+    return r
 
 
 @jax.jit
 def counts_fused(p: jnp.ndarray, y: jnp.ndarray):
-    """(c, d) from ONE sort and ONE merge-sort tree — the oracle-layer fast
-    path (core.oracle), bit-identical to `counts` / `ref.counts_ref`.
+    """(c, d) as one offline dominance count: a merge-sort tree built
+    bottom-up with the queries riding through it — the oracle-layer fast
+    path (core.oracle), bit-identical to `ref.counts_ref`.
 
-    `counts` runs the sweep twice (the d vector via the reflection
-    d(p, y) = c(-p, -y)), paying two argsorts and two tree builds. But d is
-    answerable from the *same* tree as c by complementing the margin:
+    Both frequency vectors count points that one item dominates:
 
+        c_i = |{k : y_k > y_i  and  p_k < p_i + 1}|
         d_i = |{k : y_k < y_i  and  p_k > p_i - 1}|
-            = |{k : y_k < y_i}| - |{k : y_k < y_i  and  p_k <= p_i - 1}|
 
-    The first term is the global strict y-rank (one sort + searchsorted);
-    the second is a count-less query over the prefix R_i = |{k : p_k <=
-    p_i - 1}| of the very tree built for c. `p_k <= p_i - 1` is the exact
-    float complement of the reference's `p_k > p_i - 1` (both compare
-    against the same rounded f32 value p_i - 1), so tie semantics match the
-    O(m^2) oracle bit-for-bit. Same O(m log^2 m) work bound, ~half the
-    constant: the tree build (the log^2 sort term) happens once.
+    Each example i gives three items: a c-query at key p_i + 1, a point at
+    p_i and a d-query at p_i - 1, all carrying y_i. One sort by (key, kind)
+    with kind c-query < point < d-query puts before each c-query exactly
+    the points with p_k < p_i + 1, and after each d-query exactly those
+    with p_k > p_i - 1: both compare against the same rounded f32 values
+    as the reference, so tie semantics hold bit for bit. A second sort
+    ranks the items by (y, kind) with d-query < point < c-query on equal
+    y, so "y_k > y_i" becomes "higher rank" for a c-query and "y_k < y_i"
+    "lower rank" for a d-query ('sort').
 
-    Its four phases run under the named scopes 'sort', 'tree', 'query'
-    and 'unsort', which name their operations in traces.
+    Level b merges pairs of blocks of 2^b ranks into blocks of 2^(b+1)
+    and re-sorts each by p-order position ('tree'); the left half holds
+    the lower ranks. In the merged block a left c-query adds the
+    right-half points before it and a right d-query the left-half points
+    after it, read off cumsums along the block ('query'). Every (point,
+    query) pair splits at exactly one level, so the per-item counts, which
+    ride through the sorts as a second operand, end as (c, d). After the
+    last level the items stand in p-order; one sort by item id puts the
+    counts in example order ('unsort').
+
+    Of R blocks, block r merges with block r + R/2: the items start in
+    bit-reversed rank order (slot j holds rank bitrev(j), one more sort),
+    which makes the two merged blocks adjacent rank ranges, and each
+    level's input is the previous level's two halves side by side, with
+    no reshape through a flat array. Blocks of up to 128 items are the
+    columns of a (block, blocks) array and larger ones its rows, so no
+    level pads a block to the 128 lanes of a tile. The level key packs
+    the p-order position, the kind and the side bit into a uint32.
+
+    O(m log^2 m) work, O(log m) depth, and no gather, scatter or binary
+    search: only sorts, cumsums, slices and concatenations.
     """
     p = p.astype(jnp.float32) if p.dtype == jnp.float64 else p
     m = p.shape[0]
     if m == 0:
         z = jnp.zeros((0,), jnp.int32)
         return z, z
-    with jax.named_scope('sort'):
-        order = jnp.argsort(p)
-        ps = jnp.take(p, order)
-        ys = jnp.take(y, order)
-    with jax.named_scope('tree'):
-        mpad = _next_pow2(m)
-        y_pad = jnp.pad(ys, (0, mpad - m), constant_values=jnp.inf)
-        levels = _tree_levels(y_pad)
+    n = _next_pow2(3 * m)
+    if n > _DOMINANCE_MAX_ITEMS:
+        raise ValueError(f'counts_fused holds at most '
+                         f'{_DOMINANCE_MAX_ITEMS // 3} examples; got {m}')
+    nb = n.bit_length() - 1
+    pos = jax.lax.iota(jnp.int32, n)
 
-    with jax.named_scope('query'):
-        one = jnp.asarray(1.0, ps.dtype)
-        # c: frontier p_k < p_i + 1, count y_k > y_i inside it.
-        frontier = jnp.searchsorted(ps, ps + one,
-                                    side='left').astype(jnp.int32)
-        c_sorted = _prefix_query(levels, y_pad, frontier, ys, 'gt')
-        # d: prefix p_k <= p_i - 1, count y_k < y_i inside it; subtract from
-        # the global strict rank of y_i.
-        inner = jnp.searchsorted(ps, ps - one, side='right').astype(jnp.int32)
-        lt_inner = _prefix_query(levels, y_pad, inner, ys, 'lt')
-        glt = jnp.searchsorted(jnp.sort(y), ys, side='left').astype(jnp.int32)
-        d_sorted = glt - lt_inner
+    with jax.named_scope('sort'):
+        one = jnp.asarray(1.0, p.dtype)
+        pad = n - 3 * m
+        key = jnp.concatenate([p + one, p, p - one,
+                               jnp.full((pad,), jnp.inf, p.dtype)])
+        ys = jnp.concatenate([y, y, y, jnp.zeros((pad,), y.dtype)])
+        # item id: kind * m + example
+        _, item, ys = jax.lax.sort((key, pos, ys), num_keys=2,
+                                   is_stable=False)
+        tie = 2 - jnp.minimum(item // m, _PAD)      # pad < d < point < c
+        _, rank_key = jax.lax.sort((ys, (tie << nb) | pos), num_keys=2,
+                                   is_stable=False)
+        # level key, high to low: p-order position, kind, side bit
+        kind = (2 - (rank_key >> nb)).astype(jnp.uint32)
+        key = (rank_key & (n - 1)).astype(jnp.uint32) << 3 | kind << 1
+        _, key = jax.lax.sort((_bit_reverse(pos, nb), key), num_keys=1,
+                              is_stable=False)
+
+    # blocks along axis `ax`: columns (ax = 0) while they are small
+    key, acc, ax = key.reshape(1, n), jnp.zeros((1, n), jnp.int32), 0
+    for b in range(nb):
+        rows, block = n >> (b + 1), 2 << b
+        with jax.named_scope('tree'):
+            if ax == 0 and block > _LANES:
+                key, acc, ax = key.T, acc.T, 1
+            # block r and block r + rows side by side, the second's
+            # items marked right
+            lo, hi = jnp.split(key, 2, axis=1 - ax)
+            key = jnp.concatenate([lo, hi | 1], axis=ax)
+            acc = jnp.concatenate(jnp.split(acc, 2, axis=1 - ax), axis=ax)
+            row_bits = nb - b - 1
+            if ax == 1 and rows < _TILE_ROWS and nb + 3 + row_bits <= 32:
+                # one flat sort, the row index leading the key
+                low = jnp.uint32((1 << (32 - row_bits)) - 1)
+                row = (pos >> (b + 1)).astype(jnp.uint32) * (low + 1)
+                flat, acc = jax.lax.sort((key.reshape(-1) | row,
+                                          acc.reshape(-1)),
+                                         num_keys=1, is_stable=False)
+                key = (flat & low).reshape(rows, block)
+                acc = acc.reshape(rows, block)
+            else:
+                key, acc = jax.lax.sort((key, acc), dimension=ax,
+                                        num_keys=1, is_stable=False)
+        with jax.named_scope('query'):
+            kind = (key >> 1) & 3
+            right = (key & 1) != 0
+            point = kind == _POINT
+            left_pts = jnp.cumsum(point & ~right, axis=ax, dtype=jnp.int32)
+            right_pts = jnp.cumsum(point & right, axis=ax, dtype=jnp.int32)
+            total = left_pts[-1:] if ax == 0 else left_pts[:, -1:]
+            acc = acc + (jnp.where((kind == _C_QUERY) & ~right, right_pts, 0)
+                         + jnp.where((kind == _D_QUERY) & right,
+                                     total - left_pts, 0))
+            key = key & ~jnp.uint32(1)
 
     with jax.named_scope('unsort'):
-        z = jnp.zeros((m,), jnp.int32)
-        return z.at[order].set(c_sorted), z.at[order].set(d_sorted)
+        _, acc = jax.lax.sort((item, acc.reshape(-1)), num_keys=1,
+                              is_stable=False)
+        return acc[:m], acc[2 * m:3 * m]
 
 
 @jax.jit
 def counts_grouped_fused(p: jnp.ndarray, y: jnp.ndarray, g: jnp.ndarray):
-    """Grouped (c, d) via the single-tree pass (see `counts_grouped`)."""
+    """(c, d) restricted to within-group pairs, still one linearithmic pass
+    of `counts_fused` over offset keys (`_group_offsets`).
+
+    Precision note: group offsets consume dynamic range; with float32 scores
+    keep |groups| * (range(p)+range(y)) below ~1e4 so that one ulp at the
+    largest offset key stays well under the hinge margin of 1. The reward-model
+    batch use-case (<= a few hundred groups, |p| ~ O(10)) is far inside this.
+    """
     pg, yg = _group_offsets(p, y, g)
     return counts_fused(pg, yg)
 
@@ -310,10 +395,13 @@ def counts_weighted_fused(p: jnp.ndarray, y: jnp.ndarray, v: jnp.ndarray):
     v_j of its higher-utility side, so only the c-side query is weighted —
     the d-side contribution of example j is its OWN weight v_j times the
     ordinary count d_j, applied by the caller (core.oracle, loss='poshinge').
-    The weighted levels carry the sorted-y blocks of `counts_fused`'s tree,
-    so d reuses the exact complement trick (same tie semantics bit-for-bit);
-    c~ replaces the block counts with block weight sums (`_prefix_weighted_
-    gt`). Work stays O(m log^2 m): one cumsum per level on top of the sorts.
+    The weighted levels carry the searching tree's sorted-y blocks
+    (`_tree_levels`), so d comes from c's tree by the exact complement
+        d_i = |{k : y_k < y_i}| - |{k : y_k < y_i  and  p_k <= p_i - 1}|
+    (`p_k <= p_i - 1` is the float complement of the reference's
+    `p_k > p_i - 1`, so tie semantics hold bit-for-bit); c~ replaces the
+    block counts with block weight sums (`_prefix_weighted_gt`). Work
+    stays O(m log^2 m): one cumsum per level on top of the sorts.
     """
     p = p.astype(jnp.float32) if p.dtype == jnp.float64 else p
     m = p.shape[0]
@@ -494,19 +582,6 @@ def _group_offsets(p, y, g):
     dp = (jnp.max(p) - jnp.min(p)) + jnp.asarray(2.5, p.dtype)
     dy = (jnp.max(y) - jnp.min(y)).astype(p.dtype) + jnp.asarray(1.0, p.dtype)
     return p + gf * dp, y.astype(p.dtype) + gf * dy
-
-
-@jax.jit
-def counts_grouped(p: jnp.ndarray, y: jnp.ndarray, g: jnp.ndarray):
-    """(c, d) restricted to within-group pairs, still one linearithmic pass.
-
-    Precision note: group offsets consume dynamic range; with float32 scores
-    keep |groups| * (range(p)+range(y)) below ~1e4 so that one ulp at the
-    largest offset key stays well under the hinge margin of 1. The reward-model
-    batch use-case (<= a few hundred groups, |p| ~ O(10)) is far inside this.
-    """
-    pg, yg = _group_offsets(p, y, g)
-    return counts(pg, yg)
 
 
 @jax.jit

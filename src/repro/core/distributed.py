@@ -212,7 +212,7 @@ def _scores_to_coeffs(mesh, variant: str = 'base', engine: str = 'tree'):
         if engine != 'tree':
             c, d = _counts.counts_dispatch(pk, yk, None, engine=engine)
         elif cns is None:
-            c, d = _counts.counts(pk, yk)
+            c, d = _counts.counts_fused(pk, yk)
         else:
             c = _counts._half_counts(pk, yk, constrain=cns)
             d = _counts._half_counts(-pk, -yk, constrain=cns)

@@ -18,10 +18,15 @@ appended since):
 
 where N_c counts component c's within-component pairs, R_c its pairwise
 hinge risk, g_c[i] = N_c * subgrad_c(S[i]) and ell_c[i] = N_c *
-R_c(S[i]). Summing components and dividing by the merged pair count
-yields planes that lower-bound the merged risk (cross-component pair
-losses are nonnegative and simply dropped — bounds stay valid, possibly
-looser; exact when groups never span blocks). Appending a Δ-row block
+R_c(S[i]). Summing components and dividing by the MERGED data's pair
+count N — which counts the pairs that span two components too, so
+N >= sum N_c — yields planes that lower-bound the merged risk. Those
+cross-component pairs are the gap: for the hinge their loss sum is
+convex, and one tangent of it at the last optimum w0 (two oracle
+evaluations over the merged rows at w0: with the data's groups, and with
+each group split by component) completes every plane; for 'toppush' they
+are dropped (nonnegative; bounds stay valid, possibly looser). Both are
+exact when groups never span blocks. Appending a Δ-row block
 therefore costs O(planes·Δ) oracle work instead of the O(planes·m) a
 full replan would; retiring an *appended* block is exact subtraction
 (the ledger recomputes sums from its components in canonical order, so
@@ -56,6 +61,12 @@ from ..data.rowblocks import BlockStore
 from .bmrm import (DEFAULT_MAX_PLANES, BundleState, _device_chunk,
                    bundle_state_from_planes, f32)
 from .oracle import (_loss_norm_weights, _validate_loss, make_oracle)
+
+# Losses whose cross-component loss sum (merged minus within-component)
+# is convex, so a tangent of it lower-bounds it: the hinge's, a sum of
+# pair hinges. Not 'toppush': a merged anchor's loss minus its block
+# loss is a difference of maxima.
+CROSS_TANGENT_LOSSES = ('hinge',)
 
 # Losses whose planes ARE per-block decomposable (the ledger contract):
 # the component tangent must lower-bound the component's UNNORMALIZED
@@ -230,20 +241,29 @@ class PlaneLedger:
                              f'{sorted(self._entries)}')
         del self._entries[bid]
 
-    def planes(self) -> tuple[np.ndarray, np.ndarray]:
+    def planes(self, norm: 'int | None' = None,
+               cross=None) -> tuple[np.ndarray, np.ndarray]:
         """Merged (A, b) for the current component set, float64.
 
-        A[i] = (sum of g components)[i] / N_merged and b[i] recovers the
-        offset at the stored tangent point: b[i] = ell_merged[i]/N -
-        A[i] @ S[i]. Summation runs over components in canonical
-        insertion order starting from copies of the base — never in
-        place — so the result for a given component set is a pure
-        function of that set (bit-identical round trips).
+        A[i] = (sum of g components)[i] / N and b[i] recovers the offset
+        at the stored tangent point: b[i] = ell_merged[i]/N - A[i] @ S[i].
+        N is `norm`, the merged data's loss normalizer, which counts the
+        pairs that span components too; it defaults to the components'
+        own `n_pairs`, right only when no pair spans two components.
+        `cross = (ell_x, g_x, w_x)` adds to every plane one unnormalized
+        tangent of the cross-component loss sum, taken at w_x.
+        Summation runs over components in canonical insertion order
+        starting from copies of the base — never in place — so the
+        result for a given component set is a pure function of that set
+        (bit-identical round trips).
         """
-        N = float(self.n_pairs)
+        N = float(self.n_pairs if norm is None else norm)
         if N <= 0:
             raise ValueError('ledger covers no preference pairs; nothing '
                              'to build planes from')
+        if N < self.n_pairs:
+            raise ValueError(f'merged normalizer {norm} is below the '
+                             f"components' own {self.n_pairs}")
         ell = self._base.ell.copy()
         g = self._base.g.copy()
         for e in self._entries.values():
@@ -251,6 +271,11 @@ class PlaneLedger:
             g = g + e.g
         A = g / N
         b = ell / N - np.einsum('ij,ij->i', A, self.S)
+        if cross is not None:
+            ell_x, g_x, w_x = cross
+            g_x = np.asarray(g_x, np.float64)
+            A = A + g_x / N
+            b = b + (float(ell_x) - float(g_x @ np.asarray(w_x))) / N
         return A, b
 
 
@@ -279,10 +304,11 @@ class IncrementalFit:
     """
 
     def __init__(self, store: BlockStore, state: 'BundleState | None',
-                 n_pairs: int, partials_fn=None):
+                 n_pairs: int, partials_fn=None, loss: str = 'hinge'):
         self.store = store
         self.state = state
         self._partials_fn = partials_fn or block_partials
+        self.loss = loss
         self.revalidate_seconds = 0.0
         self.ledger = None
         if state is not None and int(state.n_active) > 0 and n_pairs > 0:
@@ -335,17 +361,47 @@ class IncrementalFit:
         self.revalidate_seconds += time.perf_counter() - t0
         self.ledger = led
 
+    def planes(self, w0=None) -> tuple[np.ndarray, np.ndarray]:
+        """The ledger's planes for the retained blocks' merged risk.
+
+        They are normalized by the merged loss normalizer, which counts
+        the pairs that span two components (the base, each appended
+        block). For the hinge, given w0, each plane also carries the
+        tangent at w0 of those pairs' loss sum: the merged partials at
+        w0 less the partials with every group split by component. That
+        is two oracle evaluations over the merged rows, O(m log m), once
+        per refit."""
+        store, led = self.store, self.ledger
+        W = (np.zeros((0, led.S.shape[1])) if w0 is None
+             else np.asarray(w0, np.float64).reshape(1, -1))
+        merged = self._partials_fn(store, store.y, store.groups, W)
+        cross = None
+        if (w0 is not None and self.loss in CROSS_TANGENT_LOSSES
+                and merged.n_pairs > led.n_pairs):
+            comp = np.concatenate([
+                np.full(store.member(bid).source.m,
+                        0 if bid in led.base_bids else 1 + k)
+                for k, bid in enumerate(store.block_ids)])
+            split = comp if store.groups is None else (
+                np.unique(store.groups, return_inverse=True)[1].ravel()
+                * (len(store.block_ids) + 1) + comp)
+            within = self._partials_fn(store, store.y, split, W)
+            cross = (merged.ell[0] - within.ell[0],
+                     merged.g[0] - within.g[0], W[0])
+        return led.planes(merged.n_pairs, cross)
+
     def warm_state(self, dim: int, max_planes: int,
                    w0=None) -> 'BundleState | None':
-        """The revalidated planes as a device-driver warm start, or None
-        when there is nothing to warm from (no ledger, no planes, or no
-        pairs). Past `max_planes` the highest-dual-weight planes are
-        kept (the dual says which planes the last optimum leaned on)."""
+        """The revalidated planes (`planes(w0)`) as a device-driver warm
+        start, or None when there is nothing to warm from (no ledger, no
+        planes, or no pairs). Past `max_planes` the highest-dual-weight
+        planes are kept (the dual says which planes the last optimum
+        leaned on)."""
         if self.ledger is None or self.ledger.n_planes == 0:
             return None
         if self.ledger.n_pairs <= 0:
             return None
-        A, b = self.ledger.planes()
+        A, b = self.planes(w0)
         S, alpha = self.ledger.S, self.ledger.alpha
         K = int(max_planes)
         if A.shape[0] > K:

@@ -22,8 +22,8 @@ accelerator backends it stays on device. Either way the O(m log^2 m) counts
 and the forward matvec are device-side, and only w (in) and (loss, a) (out)
 cross the boundary.
 
-Tree counts use `counts.counts_fused` — the single-tree variant (one
-argsort + one merge-sort-tree build per oracle call instead of two) —
+Tree counts use `counts.counts_fused` — the merge-sort tree built
+bottom-up with the queries riding through its level sorts, no gathers —
 except where a different counting engine is the point (PairwiseOracle's
 blocked pass and its `counts_auto` Pallas-kernel dispatch).
 """

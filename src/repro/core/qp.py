@@ -18,10 +18,11 @@ Two implementations of the same dual:
 
 * `solve_bundle_dual`      — host numpy/float64, adaptive stopping; the
   reference path used by the host BMRM driver.
-* `solve_bundle_dual_jax`  — pure traced jax, fixed iteration count, active
-  planes selected by a boolean mask over a fixed-capacity buffer; designed
-  to run INSIDE the device driver's jitted `bundle_step` (DESIGN.md §4),
-  so the whole master-problem solve stays on device.
+* `solve_bundle_dual_jax`  — pure traced jax, a fixed iteration count and
+  then a tail to a relative Frank-Wolfe gap, active planes selected by a
+  boolean mask over a fixed-capacity buffer; designed to run INSIDE the
+  device driver's jitted `bundle_step` (DESIGN.md §4), so the whole
+  master-problem solve stays on device.
 """
 
 from __future__ import annotations
@@ -105,18 +106,30 @@ def project_simplex_masked(v: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(mask, jnp.maximum(v - theta, 0.0), 0.0)
 
 
+# The device QP's accuracy: after its `n_iter` fixed steps FISTA goes on,
+# up to `_QP_MAX_ROUNDS * n_iter` steps in all, while the dual value may
+# lie more than `_QP_RTOL` of the dual's own terms below its maximum.
+_QP_RTOL = 1e-3
+_QP_MAX_ROUNDS = 32
+
+
 def solve_bundle_dual_jax(G: jnp.ndarray, b: jnp.ndarray, lam,
                           mask: jnp.ndarray,
                           alpha0: jnp.ndarray | None = None,
                           n_iter: int = 256):
-    """Masked fixed-iteration FISTA for the bundle dual, fully traceable.
+    """Masked FISTA for the bundle dual, run to a Frank-Wolfe gap, fully
+    traceable.
 
     G is the (K, K) Gram buffer and b the (K,) offset buffer of the device
     driver's fixed-capacity bundle state; `mask` selects the active planes
-    (rows/cols outside it are ignored). Runs exactly `n_iter` FISTA steps —
-    no data-dependent early exit, so one compiled program serves every BMRM
-    iteration — and returns (alpha, dual_value) with alpha zero outside
-    `mask`. The Lipschitz constant uses the Gershgorin row-sum bound (exact
+    (rows/cols outside it are ignored). Runs `n_iter` FISTA steps, then
+    more (a `while_loop`, so one compiled program still serves every BMRM
+    iteration) while the best iterate's Frank-Wolfe gap, which bounds how
+    far its dual value lies below the maximum, exceeds `_QP_RTOL` of the
+    dual's two terms: on ill-conditioned bundles (RCV1 at lam = 1e-5) 128
+    steps left D(alpha) up to 3% of J short after 17 BMRM iterations.
+    Returns (alpha, dual_value) with alpha zero outside `mask`. The
+    Lipschitz constant uses the Gershgorin row-sum bound (exact
     eigen-decomposition is host-only); FISTA being non-monotone, the best
     iterate seen is tracked and returned.
     """
@@ -163,5 +176,16 @@ def solve_bundle_dual_jax(G: jnp.ndarray, b: jnp.ndarray, lam,
         return alpha_new, z, tk_new, a_best, f_best
 
     init = (alpha, alpha, jnp.asarray(1.0, dt), alpha, fval(alpha))
-    _, _, _, a_best, f_best = jax.lax.fori_loop(0, n_iter, body, init)
+    carry = jax.lax.fori_loop(0, n_iter, body, init)
+
+    def unconverged(c):
+        k, (_, _, _, a, _) = c
+        g = grad(a)
+        # Frank-Wolfe gap: an upper bound on fval(a) - min fval
+        fw_gap = g @ a - jnp.min(jnp.where(mask, g, jnp.inf))
+        scale = jnp.abs(bm @ a) + a @ Gm @ a / (4.0 * lam)
+        return (k < _QP_MAX_ROUNDS * n_iter) & (fw_gap > _QP_RTOL * scale)
+
+    _, (_, _, _, a_best, f_best) = jax.lax.while_loop(
+        unconverged, lambda c: (c[0] + 1, body(c[0], c[1])), (n_iter, carry))
     return a_best, -f_best

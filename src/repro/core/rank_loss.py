@@ -15,7 +15,7 @@ optimizers (SGD/Adam/BMRM) require.
 
 The `group_ids` argument restricts pairs to a single ranking group (e.g. one
 query / one prompt) while keeping a single dense linearithmic pass — see
-core.counts.counts_grouped.
+core.counts.counts_grouped_fused.
 """
 
 from __future__ import annotations
@@ -51,11 +51,11 @@ def _loss_from_counts(p, c, d, n):
 def _forward(scores, utilities, group_ids):
     p = scores.astype(jnp.float32)
     if group_ids is None:
-        c, d = _counts.counts(p, utilities)
+        c, d = _counts.counts_fused(p, utilities)
         n = jnp.maximum(_counts.num_pairs(utilities), 1.0)
     else:
         group_ids = _compact_ids(group_ids)
-        c, d = _counts.counts_grouped(p, utilities, group_ids)
+        c, d = _counts.counts_grouped_fused(p, utilities, group_ids)
         n = jnp.maximum(_counts.num_pairs_grouped(utilities, group_ids), 1.0)
     return _loss_from_counts(p, c, d, n), (c, d, n)
 

@@ -268,7 +268,8 @@ class RankSVM:
             self.report_ = self._report(res, dt)
             self.incremental_ = IncrementalFit(store, res.state,
                                                self._ledger_norm(oracle),
-                                               partials_fn=self._partials)
+                                               partials_fn=self._partials,
+                                               loss=self.loss)
         return self
 
     def path(self, X, y, lams, groups=None, mode: str = 'auto',
@@ -342,7 +343,8 @@ class RankSVM:
         self.lam = last.lam
         self.incremental_ = IncrementalFit(store, results[-1].state,
                                            self._ledger_norm(oracle),
-                                           partials_fn=self._partials)
+                                           partials_fn=self._partials,
+                                           loss=self.loss)
         return points
 
     def refit(self, X=None, y=None, groups=None, *, retire=(),

@@ -59,7 +59,7 @@ def counts_auto(p: jnp.ndarray, y: jnp.ndarray):
     pass for both frequency vectors, with its own in-trace tree
     fallback when the distinct-y alphabet overflows the histogram.
 
-    Other backends: the single-tree merge-sort pass (`counts_fused`).
+    Other backends: the gather-free merge-sort count (`counts_fused`).
     The rank-counts kernel only runs through the Pallas interpreter off
     TPU; its measured interpret-mode per-call win at mid m does not
     survive the extra compile latency and inverts at m ~ 1e6, so

@@ -1,9 +1,9 @@
 """Pallas TPU kernel: fused sub-quadratic RankSVM frequency counts.
 
 One tiled on-chip pass produces BOTH of the paper's frequency vectors
-(c, d) — the `counts_fused` complement trick moved into a kernel. The
-host-side wrapper (ops.py) sorts the scores once and precomputes two
-static structures that replace the paper's red-black tree:
+(c, d) — the searching tree's complement trick (DESIGN.md §2) moved
+into a kernel. The host-side wrapper (ops.py) sorts the scores once and
+precomputes two static structures that replace the paper's red-black tree:
 
   * the scores and compact y-ranks in sorted-p order, reshaped to
     hardware-aligned (rows, 128) tiles and kept VMEM-resident whole;

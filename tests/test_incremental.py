@@ -112,25 +112,49 @@ def test_ledger_planes_need_pairs():
 
 def test_revalidated_planes_lower_bound_merged_risk():
     """The invariant everything rests on: after appending a block, every
-    merged plane satisfies a_i @ w + b_i <= R_merged(w) at arbitrary w
-    (exact here — ungrouped data has no cross-block groups, so no pair
-    losses are dropped)."""
+    merged plane satisfies a_i @ w + b_i <= R_merged(w) at arbitrary w.
+    Ungrouped data makes every base-block pair a cross-component pair:
+    the planes are normalized by the merged pair count, and with w0 each
+    carries the cross pairs' tangent at w0, exact there."""
     base, Xd, yd = _drift(m=400)
     svm = _fit(base.X, base.y)
     inc = svm.incremental_
     inc.append(Xd, yd)
-    A, b = inc.ledger.planes()
     Xm = np.concatenate([np.asarray(base.X), Xd])
     ym = np.concatenate([base.y, yd])
     merged = O.make_oracle(Xm, ym, method='tree')
     rng = np.random.default_rng(5)
-    probes = [np.zeros(A.shape[1]), svm.w_,
-              *(rng.normal(size=A.shape[1]) for _ in range(4))]
-    for w in probes:
+    probes = [np.zeros(Xm.shape[1]), svm.w_,
+              *(rng.normal(size=Xm.shape[1]) for _ in range(4))]
+    for A, b in (inc.planes(), inc.planes(w0=svm.w_)):
+        for w in probes:
+            risk, _ = merged.loss_and_subgrad(w)
+            cuts = A @ w + b
+            # slack for the f32 device state the base planes were read from
+            assert cuts.max() <= (float(risk)
+                                  + 1e-4 * max(1.0, abs(float(risk))))
+
+
+def test_revalidated_toppush_planes_lower_bound_merged_risk():
+    """'toppush' planes stay lower bounds after an ungrouped append: the
+    merged normalizer counts every anchored example, and the dropped
+    cross-block part (a merged anchor's loss less its block loss) is
+    nonnegative, so no tangent of it is added."""
+    base, Xd, yd = _drift(m=300, seed=4)
+    svm = _fit(base.X, base.y, loss='toppush')
+    inc = svm.incremental_
+    inc.append(Xd, yd)
+    Xm = np.concatenate([np.asarray(base.X), Xd])
+    ym = np.concatenate([base.y, yd])
+    merged = O.make_oracle(Xm, ym, method='tree', loss='toppush')
+    A, b = inc.planes(w0=svm.w_)
+    np.testing.assert_array_equal(A, inc.planes()[0])
+    rng = np.random.default_rng(6)
+    for w in [np.zeros(Xm.shape[1]), svm.w_,
+              *(rng.normal(size=Xm.shape[1]) for _ in range(4))]:
         risk, _ = merged.loss_and_subgrad(w)
-        cuts = A @ w + b
-        # slack for the f32 device state the base planes were read from
-        assert cuts.max() <= float(risk) + 1e-4 * max(1.0, abs(float(risk)))
+        assert (A @ w + b).max() <= (float(risk)
+                                     + 1e-4 * max(1.0, abs(float(risk))))
 
 
 def test_block_partials_matches_scaled_oracle():
